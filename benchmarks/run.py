@@ -2,36 +2,11 @@
 Prints ``name,us_per_call,derived`` CSV. Sections:
   paper reproduction (Table 1/2/4, Fig 2/4/5/6/8/9/10/11)
   kernels + wansync micro-benches
-  roofline summary (reads the dry-run JSONs when present)
 """
 from __future__ import annotations
 
-import json
-import os
 import sys
 import time
-
-
-def _roofline_rows():
-    rows = []
-    base = os.path.join(os.path.dirname(__file__), "results")
-    for mesh in ("single", "multi"):
-        p = os.path.join(base, f"dryrun_{mesh}_wanify.json")
-        if not os.path.exists(p):
-            continue
-        with open(p) as f:
-            cells = json.load(f)
-        ok = [c for c in cells if c["status"] == "ok"]
-        if not ok:
-            continue
-        worst = min(ok, key=lambda c: c["roofline"]["roofline_fraction"])
-        rows.append((f"roofline.{mesh}.cells_ok", float(len(ok)),
-                     f"of {len(cells)} "
-                     f"({sum(c['status'] == 'skipped' for c in cells)} skipped)"))
-        rows.append((f"roofline.{mesh}.worst_fraction",
-                     worst["roofline"]["roofline_fraction"],
-                     f"{worst['arch']}x{worst['shape']}"))
-    return rows
 
 
 def main() -> None:
@@ -50,7 +25,6 @@ def main() -> None:
         paper_tables.bench_fig4_ml,
         kernels_bench.bench_kernels,
         kernels_bench.bench_wansync_model,
-        _roofline_rows,
     ]
     print("name,us_per_call,derived")
     for b in benches:

@@ -20,7 +20,7 @@ os.environ.setdefault("XLA_FLAGS",
 import jax
 import numpy as np
 
-from repro import compat
+from repro.launch.mesh import auto_mesh
 from repro.configs import get_config
 from repro.configs.base import reduced
 from repro.core.global_opt import global_optimize
@@ -68,7 +68,7 @@ def main():
 
     print("\n== 5. 2-pod training with WANify-scheduled gradient sync ==")
     cfg = reduced(get_config("llama3-8b"))
-    mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
     tr = Trainer(cfg, mesh,
                  DataConfig(batch=8, seq=32, vocab=cfg.vocab, n_pods=2),
                  LoopConfig(steps=6, sync="wanify", compress=True),

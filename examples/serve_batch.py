@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro.launch.mesh import auto_mesh
 from repro.configs import get_config
 from repro.configs.base import reduced
 from repro.control import WanifyController
@@ -63,7 +63,7 @@ def main():
     # ---- disaggregated serving: migrate the prefill KV cache across
     # pods over the WANify-scheduled links (chunked + quantized wire) ---
     print("[serve] KV migration across 2 pods (WANify schedule) ...")
-    mesh = compat.make_mesh((2, 4), ("pod", "data"))
+    mesh = auto_mesh((2, 4), ("pod", "data"))
     print(f"[serve] plan: conns={eng.plan.conns} "
           f"schedule={eng.migration_schedule()}")
     cache = jax.tree.map(jnp.asarray, eng.cache)
@@ -71,10 +71,9 @@ def main():
     def migrate(c):
         return kv_migrate(c, eng.plan, src_pod=0, compress=True)
 
-    sm = compat.shard_map(migrate, mesh=mesh, in_specs=(P(),),
-                          out_specs=P(), axis_names={"pod", "data"},
-                          check_vma=False)
-    with compat.use_mesh(mesh):
+    sm = jax.shard_map(migrate, mesh=mesh, in_specs=(P(),),
+                       out_specs=P(), axis_names={"pod"}, check_vma=False)
+    with jax.set_mesh(mesh):
         moved = jax.jit(sm)(cache)
     ok = jax.tree.all(jax.tree.map(
         lambda a, b: bool(jnp.allclose(a.astype(jnp.float32),

@@ -17,7 +17,7 @@ import time
 
 import jax
 
-from repro import compat
+from repro.launch.mesh import auto_mesh
 from repro.configs import get_config
 from repro.core.predictor import BwPredictor
 from repro.data.pipeline import DataConfig
@@ -47,7 +47,7 @@ def main():
                 jax.random.key(0))))
     print(f"[e2e] model: {n_params / 1e6:.1f}M params")
 
-    mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
     print("[e2e] training RF predictor ...")
     rf, acc, _ = train_default_forest(n_samples=150, n_trees=50)
     sim = WanSimulator(seed=0)

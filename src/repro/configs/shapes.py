@@ -39,8 +39,7 @@ SHAPE_NAMES = list(SHAPES)
 
 
 def applicable(cfg: ModelConfig, shape_name: str) -> Optional[str]:
-    """None if the (arch, shape) cell runs; otherwise the skip reason
-    (recorded in EXPERIMENTS.md / DESIGN.md)."""
+    """None if the (arch, shape) cell runs; otherwise the skip reason."""
     spec = SHAPES[shape_name]
     if spec.name == "long_500k" and not cfg.subquadratic:
         return ("full quadratic attention: 512k-token decode cache/attention "

@@ -42,7 +42,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.local_opt import SIGNIFICANT_MBPS
 from repro.core.predictor import forest_predict_jnp
@@ -500,7 +499,7 @@ class FusedFleet:
         host-side concept)."""
         single, bg = make_schedule(self.sim, steps, events)
         st = self.state()
-        with enable_x64():
+        with jax.enable_x64(True):
             (cons, target), outs = self._scan_fn(detail=True)(
                 (jnp.asarray(st.cons), jnp.asarray(st.target)),
                 jnp.asarray(single), jnp.asarray(bg))
@@ -527,7 +526,7 @@ class FusedFleet:
             scan = self._scan_fn(detail=False)
             self._scan_cache["sweep"] = jax.jit(
                 jax.vmap(scan, in_axes=(None, 0, 0)))
-        with enable_x64():
+        with jax.enable_x64(True):
             _, outs = self._scan_cache["sweep"](
                 (jnp.asarray(st.cons), jnp.asarray(st.target)),
                 jnp.asarray(singles), jnp.asarray(bgs))

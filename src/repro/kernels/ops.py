@@ -1,16 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-Interpret-vs-compiled is BACKEND-AWARE by default: on a TPU backend the
-kernels compile through the Pallas TPU lowering; on CPU/GPU containers
-they run in interpret mode (same kernel body, correctness-equivalent).
-``REPRO_PALLAS_INTERPRET`` overrides the automatic choice in either
-direction — set ``0`` to force compiled lowering (e.g. TPU CI that
-masquerades as CPU during import) or ``1`` to force interpret mode on
-a TPU (kernel debugging); leave it unset to trust the backend probe.
+Each kernel compiles through the Pallas TPU lowering, and runs in
+interpret mode only on the CPU backend (`repro.kernels.interpret_default`).
 """
 from __future__ import annotations
 
-import os
 from typing import Tuple
 
 import jax
@@ -21,45 +15,25 @@ from repro.kernels import rf_predict as _rf
 from repro.kernels import ssd_scan as _ssd
 
 
-def interpret_mode() -> bool:
-    """Resolve interpret-vs-compiled LAZILY (first kernel call, not
-    import): probing `jax.default_backend()` initializes and locks the
-    JAX platform, which must not happen as an import side effect. The
-    env var wins; otherwise the backend probe decides, memoized."""
-    global _INTERPRET
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env != "0"
-    if _INTERPRET is None:
-        _INTERPRET = _rf.default_interpret()
-    return _INTERPRET
-
-
-_INTERPRET: bool = None
-
-
 def quantize(x: jax.Array, bits: int = 8, block: int = _q.BLOCK
              ) -> Tuple[jax.Array, jax.Array]:
     """Block-symmetric quantize x -> (payload, per-tile scales)."""
-    return _q.quantize_pallas(x, bits=bits, block=block,
-                              interpret=interpret_mode())
+    return _q.quantize_pallas(x, bits=bits, block=block)
 
 
 def dequantize(q: jax.Array, scale: jax.Array, block: int = _q.BLOCK,
                out_dtype=jnp.float32) -> jax.Array:
     """Invert :func:`quantize` back to `out_dtype`."""
-    return _q.dequantize_pallas(q, scale, block=block, out_dtype=out_dtype,
-                                interpret=interpret_mode())
+    return _q.dequantize_pallas(q, scale, block=block, out_dtype=out_dtype)
 
 
 def rf_predict(feat: jax.Array, thr: jax.Array, leaf: jax.Array,
                X: jax.Array, depth: int) -> jax.Array:
     """Forest inference over packed trees: X [n, F] -> [n]."""
-    return _rf.rf_predict_pallas(feat, thr, leaf, X, depth=depth,
-                                 interpret=interpret_mode())
+    return _rf.rf_predict_pallas(feat, thr, leaf, X, depth=depth)
 
 
 def ssd_chunk(xq: jax.Array, Bq: jax.Array, Cq: jax.Array, da: jax.Array
               ) -> Tuple[jax.Array, jax.Array]:
     """One SSD chunk scan step (see kernels/ssd_scan.py)."""
-    return _ssd.ssd_chunk_pallas(xq, Bq, Cq, da, interpret=interpret_mode())
+    return _ssd.ssd_chunk_pallas(xq, Bq, Cq, da)

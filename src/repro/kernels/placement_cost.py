@@ -9,10 +9,10 @@ it; the numpy path stays the bit-exact default).
 The program is the same packed evaluation the numpy core runs —
 einsum-style shuffle volumes ``vol[m,i,j] = held[m,i] * frac[m,j]``,
 broadcast bottleneck max over off-diagonal pairs, per-source egress
-pricing — jit-compiled under 64-bit mode (`jax.experimental.
-enable_x64`, so magnitudes match the float64 reference; reductions may
-still differ in the last ulp, which is why decisions — not raw metric
-bytes — are what the cross-backend tests pin).
+pricing — jit-compiled under 64-bit mode (`jax.enable_x64`, so
+magnitudes match the float64 reference; reductions may still differ in
+the last ulp, which is why decisions — not raw metric bytes — are what
+the cross-backend tests pin).
 
 Launch shapes are BUCKETED like the controller's plan cache: the
 candidate count M is padded up to a power-of-two bucket (min 64) with
@@ -29,7 +29,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 _MIN_BUCKET = 64
 _TRACES = 0
@@ -117,7 +116,7 @@ def eval_packed_jax(placements: np.ndarray, bw: np.ndarray,
         if a.ndim == per_cand_ndim:          # per-candidate: pad rows
             return _pad_rows(a, m_pad)
         return a[None]                       # shared: broadcast dim 1
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _eval_jit(
             _pad_rows(np.asarray(placements, np.float64), m_pad),
             lift(bw, 3), lift(inputs, 2), lift(speed, 2), lift(price, 2),

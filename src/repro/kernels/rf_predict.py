@@ -3,20 +3,21 @@
 TPU adaptation of tree traversal (DESIGN.md §2): trees live in a
 COMPLETE-binary-tree array layout, so level-order descent is pure index
 arithmetic (node -> 2*node+1+go_right) — no pointers, no data-dependent
-control flow. Gathers are expressed as ONE-HOT CONTRACTIONS (VPU/MXU
-friendly; TPU Pallas has no efficient dynamic row gather), which is the
-idiomatic TPU formulation for small tables:
+control flow. Gathers are expressed as ONE-HOT SELECTS reduced along
+the lanes (TPU Pallas has no efficient dynamic row gather):
 
-  thr[t, node_s]  ==  sum_k onehot(node_s)[k] * thr[t, k]
+  thr[t, node_s]  ==  sum_k where(k == node_s, thr[t, k], 0)
+
+Exactly one term is nonzero, so the gather is exact in f32 (a matmul
+contraction would round thresholds through the MXU's bf16 passes).
 
 Grid: one cell per sample block; the whole forest (feat/thr/leaf) is
-resident in VMEM per cell (e.g. 100 trees x depth 8 ~= 0.4 MB).
+resident in VMEM per cell (e.g. 100 trees x depth 8 ~= 0.4 MB). The
+node axis is padded to whole 128-lane tiles, and each block writes its
+predictions as a [block, 1] column of a 2-D output.
 
-Backend selection: ``interpret=None`` (the default) resolves per
-backend — compiled Pallas on TPU, interpret mode elsewhere (CPU/GPU
-containers run the same kernel body for correctness). Pass an explicit
-bool to force either path; `repro.kernels.ops` additionally honors the
-``REPRO_PALLAS_INTERPRET`` environment variable.
+``interpret=None`` (the default) runs the interpreter only on the CPU
+backend (`repro.kernels.interpret_default`).
 """
 from __future__ import annotations
 
@@ -26,47 +27,46 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_default
+
 SAMPLE_BLOCK = 128
-
-
-def default_interpret() -> bool:
-    """Backend-aware interpret default: compiled on TPU, interpret
-    everywhere else (the kernel targets the TPU lowering; interpret
-    executes the same body where no TPU is present)."""
-    return jax.default_backend() != "tpu"
+LANES = 128
 
 
 def _rf_kernel(feat_ref, thr_ref, leaf_ref, x_ref, out_ref, *, depth: int,
-               n_trees: int):
+               n_trees: int, n_nodes: int):
     X = x_ref[...].astype(jnp.float32)            # [BS, F]
     BS, F = X.shape
-    NN = thr_ref.shape[1]                          # 2^depth - 1
-    NL = leaf_ref.shape[1]                         # 2^depth
+    node_k = jax.lax.broadcasted_iota(jnp.int32, (BS, thr_ref.shape[1]), 1)
+    feat_k = jax.lax.broadcasted_iota(jnp.int32, (BS, F), 1)
+    leaf_k = jax.lax.broadcasted_iota(jnp.int32, (BS, leaf_ref.shape[1]), 1)
+
+    def pick(hot, row):
+        """Exact one-hot gather: [BS, K] mask x [1|BS, K] -> [BS, 1]."""
+        return jnp.sum(jnp.where(hot, row, 0.0), axis=1, keepdims=True)
 
     def tree_body(t, acc):
         """Descend all samples through tree `t`; add its leaf values."""
-        feat_t = feat_ref[t, :]                    # [NN] int32
-        thr_t = thr_ref[t, :]                      # [NN] f32
-        leaf_t = leaf_ref[t, :]                    # [NL] f32
-        node = jnp.zeros((BS,), jnp.int32)
+        feat_t = feat_ref[pl.ds(t, 1), :].astype(jnp.float32)  # [1, NN]
+        thr_t = thr_ref[pl.ds(t, 1), :]                        # [1, NN]
+        leaf_t = leaf_ref[pl.ds(t, 1), :]                      # [1, NL]
+        node = jnp.zeros((BS, 1), jnp.int32)
         for _ in range(depth):
-            oh = (node[:, None] == jax.lax.broadcasted_iota(
-                jnp.int32, (BS, NN), 1)).astype(jnp.float32)    # [BS,NN]
-            f_s = oh @ feat_t.astype(jnp.float32)               # [BS]
-            t_s = oh @ thr_t                                    # [BS]
-            f_i = jnp.maximum(f_s, 0.0).astype(jnp.int32)
-            fh = (f_i[:, None] == jax.lax.broadcasted_iota(
-                jnp.int32, (BS, F), 1)).astype(jnp.float32)     # [BS,F]
-            x_s = jnp.sum(fh * X, axis=1)                       # [BS]
-            go_right = (x_s > t_s).astype(jnp.int32)
+            hot = node == node_k
+            f_i = jnp.maximum(pick(hot, feat_t), 0.0).astype(jnp.int32)
+            x_s = pick(f_i == feat_k, X)
+            go_right = (x_s > pick(hot, thr_t)).astype(jnp.int32)
             node = 2 * node + 1 + go_right
-        lidx = node - (NN)                                       # leaf index
-        lh = (lidx[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (BS, NL), 1)).astype(jnp.float32)
-        return acc + lh @ leaf_t
+        return acc + pick(node - n_nodes == leaf_k, leaf_t)
 
-    acc = jax.lax.fori_loop(0, n_trees, tree_body, jnp.zeros((BS,), jnp.float32))
+    acc = jax.lax.fori_loop(0, n_trees, tree_body,
+                            jnp.zeros((BS, 1), jnp.float32))
     out_ref[...] = acc / n_trees
+
+
+def _pad_lanes(a: jax.Array) -> jax.Array:
+    """Pad the node axis to whole lane tiles (padding is never selected)."""
+    return jnp.pad(a, ((0, 0), (0, (-a.shape[1]) % LANES)))
 
 
 @functools.partial(jax.jit,
@@ -74,32 +74,29 @@ def _rf_kernel(feat_ref, thr_ref, leaf_ref, x_ref, out_ref, *, depth: int,
 def rf_predict_pallas(feat: jax.Array, thr: jax.Array, leaf: jax.Array,
                       X: jax.Array, depth: int, block: int = SAMPLE_BLOCK,
                       interpret: bool = None) -> jax.Array:
-    """feat/thr [T, 2^d-1], leaf [T, 2^d], X [n, F] -> [n] predictions.
-
-    ``interpret=None`` resolves via :func:`default_interpret` (compiled
-    on TPU, interpret elsewhere); it is a static argument, so each
-    resolved value compiles once.
-    """
+    """feat/thr [T, 2^d-1], leaf [T, 2^d], X [n, F] -> [n] predictions."""
     if interpret is None:
-        interpret = default_interpret()
+        interpret = interpret_default()
     n, F = X.shape
-    T = feat.shape[0]
+    T, n_nodes = feat.shape
+    feat, thr, leaf = _pad_lanes(feat), _pad_lanes(thr), _pad_lanes(leaf)
     pad = (-n) % block
     if pad:
         X = jnp.pad(X, ((0, pad), (0, 0)))
     npad = X.shape[0]
-    grid = (npad // block,)
     out = pl.pallas_call(
-        functools.partial(_rf_kernel, depth=depth, n_trees=T),
-        grid=grid,
+        functools.partial(_rf_kernel, depth=depth, n_trees=T,
+                          n_nodes=n_nodes),
+        grid=(npad // block,),
         in_specs=[
             pl.BlockSpec(feat.shape, lambda i: (0, 0)),
             pl.BlockSpec(thr.shape, lambda i: (0, 0)),
             pl.BlockSpec(leaf.shape, lambda i: (0, 0)),
             pl.BlockSpec((block, F), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((npad,), jnp.float32),
+        out_specs=pl.BlockSpec((block, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((npad, 1), jnp.float32),
         interpret=interpret,
+        name="rf_predict",
     )(feat, thr, leaf, X)
-    return out[:n]
+    return out[:n, 0]

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_default
+
 HEAD_BLOCK = 8
 
 
@@ -58,9 +60,11 @@ def _ssd_kernel(xq_ref, bq_ref, cq_ref, da_ref, y_ref, st_ref):
 @functools.partial(jax.jit, static_argnames=("head_block", "interpret"))
 def ssd_chunk_pallas(xq: jax.Array, Bq: jax.Array, Cq: jax.Array,
                      da: jax.Array, head_block: int = HEAD_BLOCK,
-                     interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                     interpret: bool = None) -> Tuple[jax.Array, jax.Array]:
     """Batched over (B, nC): xq [B,nC,Q,H,P], Bq/Cq [B,nC,Q,N],
     da [B,nC,H,Q] -> (y_diag [B,nC,Q,H,P], states [B,nC,H,P,N])."""
+    if interpret is None:
+        interpret = interpret_default()
     B, nC, Q, H, P = xq.shape
     N = Bq.shape[-1]
     BH = min(head_block, H)

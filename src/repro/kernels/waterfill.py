@@ -21,7 +21,7 @@ over `[B, N, N]` AGGREGATE-connection tensors:
     per-fill iteration count and a convergence flag are returned so a
     non-converging fill FAILS LOUDLY instead of returning partial
     rates (mirroring the simulator's `last_fill_iters` contract);
-  * arithmetic is float64 under `jax.experimental.enable_x64`, so
+  * arithmetic is float64 under `jax.enable_x64`, so
     rates match the numpy reference to roundoff (the hypothesis
     property in tests/test_waterfill_kernel.py pins atol/rtol);
 
@@ -40,7 +40,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 EPS_DEN = 1e-12          # weight-denominator clip (matches numpy)
 EPS_INC = 1e-9           # smallest meaningful fill-level increment
@@ -137,7 +136,7 @@ def fill_rates(c: np.ndarray, single: np.ndarray, egress: np.ndarray,
     program per (batch-shape, N). Returns numpy ``(rate, iters,
     converged)`` with the same leading shape.
     """
-    with enable_x64():
+    with jax.enable_x64(True):
         rate, iters, ok = _fill_jit(
             jnp.asarray(c, jnp.float64), jnp.asarray(single, jnp.float64),
             jnp.asarray(egress, jnp.float64),

@@ -1,18 +1,19 @@
 """Multi-pod dry-run: .lower().compile() every (arch x shape x mesh)
 cell on the production meshes, record memory/cost analysis + collective
-bytes, and emit the roofline table (EXPERIMENTS.md §Dry-run/§Roofline).
+bytes, and emit the static roofline table.
 
   PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-8b \
       --shape train_4k --mesh single
   PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both \
       --out benchmarks/results
 
-NOTE the first two executable lines below: they MUST run before any jax
+NOTE the first three executable lines below: they MUST run before any jax
 import (jax locks the device count on first init). The 512 placeholder
 host devices exist ONLY for the dry-run; smoke tests / benches see 1.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"      # a CPU-only tool: never a chip
 # (no `from __future__` here: the env var lines above must be the first
 # executable statements in the module)
 
@@ -28,7 +29,6 @@ import numpy as np
 
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.shapes import SHAPES, applicable, input_specs
 from repro.core.plan import WanPlan
@@ -166,15 +166,13 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str,
         return cell
     t0 = time.time()
     try:
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered, meta = build_lowered(arch, shape_name, mesh, **kw)
             t_lower = time.time() - t0
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):   # jax 0.4.x: per-device list
-                cost = cost[0] if cost else {}
             hlo = compiled.as_text()
         pod_stride = 256 if "pod" in mesh.axis_names else 1 << 60
         # trip-count-weighted static analysis (XLA cost_analysis counts
@@ -224,7 +222,7 @@ def _run_cell_subprocess(arch, shape, args, mesh_name):
         cmd.append("--no-compress")
     if args.no_seq_shard:
         cmd.append("--no-seq-shard")
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)               # let the child set its own
     r = subprocess.run(cmd, capture_output=True, text=True, env=env,
                        timeout=3600)

@@ -12,7 +12,7 @@ of enclosing ``known_trip_count``s, and accumulates:
     instructions; fusion internals cost 0 bytes (VMEM/registers)
   * collective bytes by kind, split intra-pod (ICI) / inter-pod (DCI)
 
-All weighted by loop multiplicity. This feeds EXPERIMENTS.md §Roofline.
+All weighted by loop multiplicity. This feeds `repro.launch.roofline`.
 """
 from __future__ import annotations
 

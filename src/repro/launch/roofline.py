@@ -1,4 +1,4 @@
-"""Roofline analysis from dry-run artifacts (EXPERIMENTS.md §Roofline).
+"""Roofline analysis from dry-run artifacts.
 
 Terms (seconds, per chip — cost_analysis of the SPMD module is already
 per-partition):

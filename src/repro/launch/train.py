@@ -3,8 +3,8 @@
   PYTHONPATH=src python -m repro.launch.train --arch llama3-8b --reduced \
       --steps 50 --pods 2 --data 2 --model 2 --sync wanify --compress
 
-On this CPU container use --reduced (small same-family config) and a
-small mesh; on real hardware drop --reduced and use the production mesh.
+On the CPU use --reduced (small same-family config) and a small mesh;
+on TPU hosts drop --reduced and use the production mesh.
 """
 import os
 
@@ -22,6 +22,7 @@ from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import reduced as reduce_cfg
 from repro.core.predictor import BwPredictor
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.train.loop import LoopConfig, Trainer
 from repro.train.optimizer import AdamWConfig
@@ -47,6 +48,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)

@@ -211,7 +211,7 @@ def gqa_forward(p: Dict, x: jax.Array, ctx: ShardCtx, cfg: ModelConfig,
     # axis, and XLA then REPLICATES every per-block score tensor in the
     # flash scans (~2 GiB x layers x blocks of all-gather traffic).
     # Expanded [B,H,S,*] shards H/TP cleanly; the repeat's VJP sums dk/dv
-    # back over groups. (EXPERIMENTS.md §Perf iteration 1.)
+    # back over groups.
     G = H // KV
     if G > 1:
         k = jnp.repeat(k, G, axis=1)

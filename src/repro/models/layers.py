@@ -51,18 +51,10 @@ def shard_act(x: jax.Array, ctx: ShardCtx) -> jax.Array:
 
 
 # ----------------------------------------------------------------------
-# f32-accumulating einsum.
-# On TPU the MXU takes bf16 inputs and accumulates f32
-# (preferred_element_type). XLA-CPU's DotThunk lacks BF16xBF16=F32 for
-# some shapes, so on CPU we cast inputs to f32 (exact superset of the
-# TPU numerics; documented in EXPERIMENTS.md SSDry-run notes).
+# f32-accumulating einsum: the MXU takes bf16 inputs and accumulates f32
+# (preferred_element_type); XLA-CPU runs the same dot.
 # ----------------------------------------------------------------------
-_ON_CPU = jax.default_backend() == "cpu"
-
-
 def einsum_f32(spec: str, *ops: jax.Array) -> jax.Array:
-    if _ON_CPU:
-        return jnp.einsum(spec, *[o.astype(jnp.float32) for o in ops])
     return jnp.einsum(spec, *ops, preferred_element_type=jnp.float32)
 
 
@@ -72,8 +64,7 @@ def einsum_f32(spec: str, *ops: jax.Array) -> jax.Array:
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     """Stats in f32, VALUE path in the compute dtype: a full-f32 value
     path makes every activation gradient f32, doubling the bytes of all
-    TP/SP collectives touching [B,S,d] tensors
-    (EXPERIMENTS.md §Perf iteration 2)."""
+    TP/SP collectives touching [B,S,d] tensors."""
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     inv = jax.lax.rsqrt(var + eps).astype(x.dtype)
     return x * inv * scale.astype(x.dtype)

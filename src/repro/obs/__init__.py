@@ -7,9 +7,8 @@ trace golden replays byte-identical with obs on.
 """
 from repro.obs.export import (OBS_SCHEMA, check_run, diff_runs,
                               export_run, export_scenario, flatten,
-                              load, render_dryrun_summary,
-                              render_dryrun_table, summarize, to_json,
-                              write_json, write_spans_jsonl)
+                              load, render_dryrun_table, summarize,
+                              to_json, write_json, write_spans_jsonl)
 from repro.obs.registry import (Counter, Gauge, Histogram,
                                 MetricsRegistry, Series)
 from repro.obs.sle import (SLE_BAND, accuracy_sle, capacity_sle, fault_sle,
@@ -28,5 +27,5 @@ __all__ = [
     "fleet_monitoring_usd", "scenario_sle", "fleet_sle",
     "export_run", "export_scenario", "to_json", "write_json",
     "write_spans_jsonl", "load", "flatten", "diff_runs", "check_run",
-    "summarize", "render_dryrun_table", "render_dryrun_summary",
+    "summarize", "render_dryrun_table",
 ]

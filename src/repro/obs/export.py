@@ -4,11 +4,9 @@ One run's observability — metric registries, span rollups, SLE health
 — exports as ONE canonical JSON document (`obs_schema` versioned,
 sorted keys), which `tools/obsctl.py` summarizes, diffs against
 another run, and gates in CI. The renderers here are the single
-human-readable report path: `benchmarks/report.py` is a thin wrapper
-over :func:`render_dryrun_summary` / :func:`render_dryrun_table`, and
-:func:`summarize` also understands the repo's `BENCH_<name>.json`
-trajectory documents, so there is one report implementation, not two
-drifting ones.
+human-readable report path: :func:`summarize` understands the repo's
+`BENCH_<name>.json` trajectory documents and the dry-run cell lists
+too, so there is one report implementation, not two drifting ones.
 """
 from __future__ import annotations
 
@@ -267,7 +265,7 @@ def summarize(doc: Any) -> str:
     return json.dumps(doc, indent=2, sort_keys=True)
 
 
-# -- the EXPERIMENTS dry-run tables (formerly benchmarks/report.py) ----
+# -- the dry-run cell table ---------------------------------------------
 def _fmt_bytes(b: float) -> str:
     return f"{b / 2 ** 30:.2f}"
 
@@ -300,27 +298,3 @@ def render_dryrun_table(cells: List[Mapping[str, Any]], mesh: str) -> str:
             f" | {r['roofline_fraction']:.3f} | {note}{dci} |")
     return "\n".join(out)
 
-
-def render_dryrun_summary(cells_by_mesh: Mapping[str, List[Mapping[str, Any]]]
-                          ) -> str:
-    """The cross-mesh dry-run summary bullets."""
-    rows = []
-    for mesh, cells in cells_by_mesh.items():
-        ok = [c for c in cells if c["status"] == "ok"]
-        if not ok:
-            continue
-        doms: Dict[str, int] = {}
-        for c in ok:
-            doms[c["roofline"]["dominant"]] = \
-                doms.get(c["roofline"]["dominant"], 0) + 1
-        worst = min(ok, key=lambda c: c["roofline"]["roofline_fraction"])
-        coll = max(ok, key=lambda c: c["roofline"]["t_collective"] /
-                   max(c["roofline"]["t_compute"] +
-                       c["roofline"]["t_memory"], 1e-12))
-        rows.append(f"- **{mesh}**: {len(ok)} ok / "
-                    f"{sum(c['status'] == 'skipped' for c in cells)} skipped; "
-                    f"dominant terms: {doms}; worst roofline fraction "
-                    f"{worst['roofline']['roofline_fraction']:.3f} "
-                    f"({worst['arch']}x{worst['shape']}); most "
-                    f"collective-bound: {coll['arch']}x{coll['shape']}")
-    return "\n".join(rows)

@@ -21,14 +21,15 @@ deployment needs:
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.checkpoint import ckpt as ckpt_lib
 from repro.configs.base import ModelConfig
 from repro.control import ControllerConfig, WanifyController
@@ -133,17 +134,20 @@ class Trainer:
                 self.events.append(f"restored step {latest}")
         if self.multi_pod:
             # vmap-over-pods formulation: explicit pod-replicated leading
-            # dim (checkpoints stay pod-free => elastic across pod counts)
+            # dim, sharded so each pod's copy lives on that pod's devices
+            # (checkpoints stay pod-free => elastic across pod counts)
             from repro.train.train_step import broadcast_to_pods
-            params = broadcast_to_pods(params, self.n_pods)
-            opt_state = broadcast_to_pods(opt_state, self.n_pods)
+            to_pods = jax.jit(
+                functools.partial(broadcast_to_pods, n_pods=self.n_pods),
+                out_shardings=NamedSharding(self.mesh, P("pod")))
+            params, opt_state = to_pods((params, opt_state))
         return params, opt_state, start
 
     # ------------------------------------------------------------------
     def run(self, key: jax.Array, fail_at: Optional[int] = None):
         """fail_at: inject a simulated node failure at that step (the
         fault-tolerance test path)."""
-        with compat.use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return self._run(key, fail_at)
 
     def _run(self, key: jax.Array, fail_at: Optional[int] = None):

@@ -101,8 +101,7 @@ def make_train_step(cfg: ModelConfig, mesh, *, plan: Optional[WanPlan] = None,
     # per device identical to replication). Per-pod grads come from
     # vmapping the loss; the WANify schedule is jnp.roll over the pod dim
     # (lowers to collective-permute). The shard_map formulation
-    # (wan_allreduce) is kept for TPU stacks — XLA-CPU CHECK-crashes on
-    # partially-manual meshes (DESIGN.md §multi-pod note).
+    # (wan_allreduce) emits the same wire pattern; kv_migrate uses it.
     # ------------------------------------------------------------------
     from repro.core.wansync import (psum_allreduce_batched,
                                     wan_allreduce_batched)

@@ -4,8 +4,8 @@ against the sequential `FleetController.tick` loop."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.global_opt import _pair_weights, global_optimize, \
     split_budget
@@ -53,7 +53,7 @@ def random_bw(rng, n):
 # ----------------------------------------------------------------------
 def test_relations_port_exact():
     rng = np.random.default_rng(0)
-    with enable_x64():
+    with jax.enable_x64(True):
         for trial in range(40):
             n = int(rng.integers(2, 9))
             bw = random_bw(rng, n)
@@ -69,7 +69,7 @@ def test_global_ranges_port_exact():
     """Eq. 2-3 + throttle + link-cap clamp: integer ranges match the
     numpy optimizer exactly, continuous outputs to roundoff."""
     rng = np.random.default_rng(1)
-    with enable_x64():
+    with jax.enable_x64(True):
         for trial in range(25):
             n = int(rng.integers(2, 7))
             bw = random_bw(rng, n)
@@ -95,7 +95,7 @@ def test_global_ranges_port_exact():
 
 def test_split_budget_port_exact():
     rng = np.random.default_rng(2)
-    with enable_x64():
+    with jax.enable_x64(True):
         for _ in range(40):
             J = int(rng.integers(1, 9))
             m = int(rng.integers(1, 33))
@@ -111,7 +111,7 @@ def test_split_budget_port_exact():
 
 def test_arbiter_ports_exact():
     rng = np.random.default_rng(3)
-    with enable_x64():
+    with jax.enable_x64(True):
         for _ in range(15):
             J, n = int(rng.integers(1, 7)), 8
             presence = rng.random((J, n)) < 0.5
@@ -131,7 +131,7 @@ def test_arbiter_ports_exact():
 def test_aimd_port_exact():
     """Every source row stepped at once == per-agent Python AIMD."""
     rng = np.random.default_rng(4)
-    with enable_x64():
+    with jax.enable_x64(True):
         for _ in range(10):
             n = int(rng.integers(2, 7))
             plan = global_optimize(random_bw(rng, n), M=8)

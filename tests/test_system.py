@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
+from repro.launch.mesh import auto_mesh
 from repro.configs import get_config
 from repro.configs.base import reduced
 from repro.data.pipeline import DataConfig
@@ -19,7 +19,7 @@ from repro.train.optimizer import AdamWConfig
 
 
 def _mesh1():
-    return compat.make_mesh((1,), ("data",))
+    return auto_mesh((1,), ("data",))
 
 
 def test_training_reduces_loss(tmp_path):
@@ -65,11 +65,11 @@ _MULTIPOD_SCRIPT = textwrap.dedent("""
     import jax, numpy as np
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import auto_mesh
     from repro.core.wansync import wan_allreduce, psum_allreduce
     from repro.core.plan import WanPlan
 
-    mesh = make_mesh((4, 2), ("pod", "data"))
+    mesh = auto_mesh((4, 2), ("pod", "data"))
     plan = WanPlan(
         n_pods=4,
         conns=tuple(tuple(6 if abs(i - j) % 4 > 1 else 2 for j in range(4))
@@ -85,11 +85,9 @@ _MULTIPOD_SCRIPT = textwrap.dedent("""
         local = jax.tree.map(lambda x: x * (r + 1.0), t)
         return wan_allreduce(local, plan, compress=False, mean=True)
 
-    # fully-manual axes: jax 0.4.x XLA-CPU cannot partition a partially-
-    # manual mesh (PartitionId unimplemented); inputs are replicated so
-    # making "data" manual too is value-identical here.
-    sm = shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                   axis_names={"pod", "data"}, check_vma=False)
+    # only the pod axis is manual; "data" stays auto, as in kv_migrate
+    sm = jax.shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                       axis_names={"pod"}, check_vma=False)
     out = jax.jit(sm)(tree)
     exp = np.mean([r + 1 for r in range(4)])
     for k in tree:
@@ -120,8 +118,8 @@ _DRYRUN_SCRIPT = textwrap.dedent("""
     import repro.launch.dryrun as dr
 
     # shrink the production mesh to the 8 host devices: same axes/logic
-    from repro.compat import make_mesh
-    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
     import repro.configs as C
     cfg = get_config("llama3-8b")
     # patch a tiny config into the registry path used by run_cell
