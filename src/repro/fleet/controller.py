@@ -114,6 +114,8 @@ class FleetController:
             self.tracer = SpanTracer()
             self.tracer.watch(self.sim.metrics)
             self.tracer.watch(self.predictor.metrics)
+        # the RF stage's launch / wait / fetch spans nest under `predict`
+        self.predictor.tracer = self.tracer
         self.faults: Optional[FaultPlane] = None
         if isinstance(faults, FaultPlane):
             self.faults = faults
@@ -295,8 +297,7 @@ class FleetController:
                             skew_w=job.skew(), reason="fleet",
                             step=self.tick_count, capture=raw, pred=pred)
                         job.view.register(job.controller.current_conns())
-            with tr.span("planners"):
-                self._flush_planners()
+            self._flush_planners()
             with tr.span("waterfill", delta=True):
                 try:
                     if self.faults is not None \

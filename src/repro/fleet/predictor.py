@@ -9,6 +9,15 @@ and the forest stays resident in VMEM across the whole batch.
 
 `kernel_calls` counts launches; the fleet invariant (asserted in
 tests/test_fleet.py) is exactly one per tick regardless of job count.
+
+With a tracer installed (the fleet installs its own when obs is on),
+each call records three spans: `rf_launch` (the rows to a float32
+device array, the jit dispatch and the request for the result's copy
+to the host), `rf_wait` (the host blocked until the kernel's result is
+ready) and `rf_fetch` (the rest of the copy back and the 1 Mbps
+floor). The copy is requested at launch, as `np.asarray` alone would
+request it before blocking, so that it follows the kernel on the device
+without waiting for the host to wake from `rf_wait`.
 """
 from __future__ import annotations
 
@@ -19,6 +28,7 @@ import numpy as np
 
 from repro.core.forest import RandomForest
 from repro.obs.registry import MetricsRegistry
+from repro.obs.spans import NULL_TRACER
 
 
 class BatchedRfPredictor:
@@ -39,6 +49,7 @@ class BatchedRfPredictor:
             "kernel_calls", help="batched RF Pallas launches")
         self._m_rows = self.metrics.counter(
             "rows_total", help="feature rows predicted")
+        self.tracer = NULL_TRACER
 
     def predict_rows(self, X: np.ndarray) -> np.ndarray:
         """Predict runtime BW for stacked feature rows [R, 6] -> [R].
@@ -47,11 +58,18 @@ class BatchedRfPredictor:
         predictions are floored at 1 Mbps (BW is positive).
         """
         from repro.kernels import ops
+        tr = self.tracer
         self._m_calls.inc()
         self._m_rows.inc(int(np.asarray(X).shape[0]))
-        vals = ops.rf_predict(*self._packed, jnp.asarray(X, jnp.float32),
-                              depth=self.forest.depth)
-        return np.maximum(np.asarray(vals, np.float64), 1.0)
+        with tr.span("rf_launch"):
+            vals = ops.rf_predict(*self._packed,
+                                  jnp.asarray(X, jnp.float32),
+                                  depth=self.forest.depth)
+            vals.copy_to_host_async()
+        with tr.span("rf_wait"):
+            vals.block_until_ready()
+        with tr.span("rf_fetch"):
+            return np.maximum(np.asarray(vals, np.float64), 1.0)
 
     @property
     def kernel_calls(self) -> int:

@@ -9,7 +9,14 @@ records a nested span per stage with
     plane touches a clock, and it flows solely into span records /
     exports, never into trace values or control decisions);
   * optional counter deltas from watched registries (fill iterations,
-    kernel launches, cache hits) on spans opened with ``delta=True``.
+    kernel launches, cache hits) on spans opened with ``delta=True``;
+  * a ``jax.profiler.TraceAnnotation`` named ``wanify.<span name>``,
+    opened just before the span's clock is read and closed just after
+    its duration is taken. Inside ``jax.profiler.trace`` the
+    annotations nest exactly like the span tree, on the same clock as
+    the device ops, so a device idle gap can be put down to the stage
+    the host was in. jax is imported at the first enabled span, so
+    this module imports without it.
 
 Gating (`REPRO_OBS=off|on`, off default, resolved by :func:`obs_mode`)
 follows the overlay/lifecycle pattern: off installs the shared
@@ -29,6 +36,8 @@ from typing import Any, Dict, List, Optional
 from repro.obs.registry import MetricsRegistry
 
 OBS_MODES = ("off", "on")
+# host annotations of enabled spans are named ANNOTATION_PREFIX + name
+ANNOTATION_PREFIX = "wanify."
 
 
 def obs_mode(mode: Optional[str] = None) -> str:
@@ -77,7 +86,7 @@ class _SpanCtx:
     """One live span: context manager that records itself on exit."""
 
     __slots__ = ("tracer", "name", "attrs", "delta", "sid", "parent",
-                 "depth", "t0", "before")
+                 "depth", "t0", "before", "annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, delta: bool,
                  attrs: Dict[str, Any]):
@@ -98,12 +107,15 @@ class _SpanCtx:
             self.before = {f"{reg.namespace}.{k}": v
                            for reg in tr._watched
                            for k, v in reg.counters().items()}
+        self.annotation = tr._annotation(ANNOTATION_PREFIX + self.name)
+        self.annotation.__enter__()
         self.t0 = tr._clock()
         return self
 
     def __exit__(self, *exc):
         tr = self.tracer
         dur = tr._clock() - self.t0
+        self.annotation.__exit__(*exc)
         tr._stack.pop()
         row: Dict[str, Any] = {
             "sid": self.sid, "parent": self.parent, "depth": self.depth,
@@ -145,6 +157,15 @@ class SpanTracer:
         self._stack: List[int] = []
         self._seq = 0
         self._watched: List[MetricsRegistry] = []
+        self._trace_annotation = None
+
+    def _annotation(self, name: str):
+        """A profiler annotation called `name`; jax is imported at the
+        first one."""
+        if self._trace_annotation is None:
+            from jax.profiler import TraceAnnotation
+            self._trace_annotation = TraceAnnotation
+        return self._trace_annotation(name)
 
     def watch(self, registry: MetricsRegistry) -> None:
         """Delta this registry's counters on ``delta=True`` spans."""

@@ -362,6 +362,105 @@ def test_fleet_obs_on_records_tick_spans():
     assert any(k.startswith("job.") for k in keys)
 
 
+def _profiled_annotations(tmp_path, body):
+    """Run `body` under ``jax.profiler.trace`` on this process's devices
+    and return the ``wanify.`` host events it wrote, as (name, start_ns,
+    end_ns) sorted by start."""
+    jax = pytest.importorskip("jax")
+    import glob
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        body()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    return sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for plane in pd.planes
+                   if not plane.name.startswith("/device:")
+                   for line in plane.lines for e in line.events
+                   if e.name.startswith("wanify.")), key=lambda r: r[1])
+
+
+def test_span_annotations_on_profiler_trace(tmp_path):
+    tr = SpanTracer()
+
+    def body():
+        with tr.span("tick"):
+            with tr.span("predict"):
+                with tr.span("rf_wait"):
+                    pass
+            with tr.span("replan"):
+                pass
+    anns = _profiled_annotations(tmp_path, body)
+    # one annotation per span, named after it, in the span tree's order
+    assert [a[0] for a in anns] == ["wanify.tick", "wanify.predict",
+                                    "wanify.rf_wait", "wanify.replan"]
+    assert len(anns) == len(tr.spans)
+    by_name = {a[0][len("wanify."):]: a for a in anns}
+    sid = {s["sid"]: s["name"] for s in tr.spans}
+    for span in tr.spans:
+        if span["parent"] < 0:
+            continue
+        _, s0, e0 = by_name[span["name"]]
+        _, s1, e1 = by_name[sid[span["parent"]]]
+        assert s1 <= s0 and e0 <= e1, (span["name"], sid[span["parent"]])
+    # the annotation holds the span: its length is at least the span's
+    for span in tr.spans:
+        _, s0, e0 = by_name[span["name"]]
+        assert e0 - s0 >= 1e9 * span["dur_s"] - 1e3
+
+
+def test_null_tracer_writes_no_annotations(tmp_path):
+    def body():
+        with NULL_TRACER.span("tick"):
+            with NULL_TRACER.span("predict"):
+                pass
+    assert _profiled_annotations(tmp_path, body) == []
+
+
+def _fleet_run(obs):
+    from repro.fleet.scenario import FleetEngine, get_fleet_scenario
+    spec = get_fleet_scenario("fleet_steady")
+    spec.steps = 2
+    eng = FleetEngine(spec, seed=0, obs=obs)
+    res = eng.run()
+    preds = [np.array(j.controller.last_pred)
+             for j in eng.fleet.jobs.values()]
+    return eng, res, preds
+
+
+def test_fleet_obs_on_splits_the_rf_stage():
+    pytest.importorskip("jax")
+    eng, _, _ = _fleet_run("on")
+    spans = eng.tracer.spans
+    by_sid = {s["sid"]: s for s in spans}
+    ticks = [s for s in spans if s["name"] == "tick"]
+    assert len(ticks) == 2
+    for name in ("rf_launch", "rf_wait", "rf_fetch"):
+        mine = [s for s in spans if s["name"] == name]
+        assert len(mine) == len(ticks), name
+        for s in mine:
+            assert by_sid[s["parent"]]["name"] == "predict"
+    # one of each, in order, under every predict span
+    for pred in (s for s in spans if s["name"] == "predict"):
+        kids = sorted((s for s in spans if s["parent"] == pred["sid"]),
+                      key=lambda s: s["t"])
+        assert [k["name"] for k in kids] == ["rf_launch", "rf_wait",
+                                             "rf_fetch"]
+    # the fleet tick no longer records a planners span
+    assert not any(s["name"] == "planners" for s in spans)
+
+
+def test_fleet_records_identical_obs_on_and_off():
+    pytest.importorskip("jax")
+    _, res_on, preds_on = _fleet_run("on")
+    _, res_off, preds_off = _fleet_run("off")
+    assert res_on.trace.to_json() == res_off.trace.to_json()
+    assert len(preds_on) == len(preds_off) > 0
+    for a, b in zip(preds_on, preds_off):
+        assert a.tobytes() == b.tobytes()
+
+
 # ----------------------------------------------------------------------
 # SLE rollups
 # ----------------------------------------------------------------------
