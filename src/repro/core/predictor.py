@@ -1,8 +1,9 @@
 """Runtime-BW prediction (paper §3.1): Table-3 feature assembly + forest
 inference. Inference has three interchangeable backends:
   numpy  — RandomForest.predict (training-side)
-  jnp    — forest_predict_jnp (jit-able, used inside controllers)
-  pallas — kernels.rf_predict (TPU kernel; validated vs the jnp oracle)
+  jnp    — forest_predict_jnp (jit-able gathers; the kernel's oracle)
+  pallas — kernels.rf_predict (TPU kernel; the fleet's sequential and
+           fused ticks both run it)
 """
 from __future__ import annotations
 

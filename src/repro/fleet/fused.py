@@ -10,8 +10,9 @@ overhead per job per tick. This module expresses the ENTIRE tick as a
 single traced program over stacked job tensors:
 
   stacked snapshot capture (one batched water-fill credits every
-  tenant) -> Table-3 feature rows -> stacked RF predict
-  (`forest_predict_jnp`) -> Algorithm-1 relations -> Eq. 2-3 ranges +
+  tenant) -> Table-3 feature rows -> stacked RF predict (the exact
+  one-hot Pallas kernel `rf_predict_pallas`, the one the sequential
+  tick launches) -> Algorithm-1 relations -> Eq. 2-3 ranges +
   §3.2.2 throttle -> priority-weighted budget split & link shares ->
   AIMD clamp -> register -> ONE fleet water-fill with per-tenant
   crediting
@@ -44,7 +45,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.local_opt import SIGNIFICANT_MBPS
-from repro.core.predictor import forest_predict_jnp
+from repro.kernels.rf_predict import SAMPLE_BLOCK, rf_predict_pallas
 from repro.kernels.waterfill import fill_rates_loop
 from repro.scenarios.events import (CrossTraffic, DiurnalCycle, LinkDegrade,
                                     LinkRestore, Timed)
@@ -87,6 +88,18 @@ def relations_jnp(bw: jax.Array, D: float) -> jax.Array:
     pick = jnp.where(jnp.abs(val - kv[lo]) <= jnp.abs(kv[hi] - val), lo, hi)
     rel = jnp.where(found, n_u - k, n_u - pick).reshape(n, n)
     return jnp.where(jnp.eye(n, dtype=bool), 1, rel).astype(jnp.int32)
+
+
+def forest_rows(feat: jax.Array, thr: jax.Array, leaf: jax.Array,
+                X: jax.Array, depth: int) -> jax.Array:
+    """The tick's stacked RF predict: float32 rows [R, 6] -> [R], before
+    the 1 Mbps floor. The Pallas kernel's row block is R rounded up to
+    whole sublanes (at most one `SAMPLE_BLOCK`), so a fleet's rows fill
+    one grid cell; under `jax.vmap` each variant becomes a grid cell of
+    its own. Each row's sum over trees runs in the order the sequential
+    tick's `BatchedRfPredictor` runs it, so both predict the same bits."""
+    block = min(-(-X.shape[0] // 8) * 8, SAMPLE_BLOCK)
+    return rf_predict_pallas(feat, thr, leaf, X, depth=depth, block=block)
 
 
 def global_ranges_jnp(bw: jax.Array, M: jax.Array, ws_pair: jax.Array,
@@ -431,7 +444,7 @@ class FusedFleet:
                 snap[:, idx_i, idx_j], mem[:, idx_j], cpu[:, idx_i],
                 retr[:, idx_i, idx_j], dists[:, idx_i, idx_j],
             ], axis=-1).reshape(J * n_pairs, 6).astype(jnp.float32)
-            vals = forest_predict_jnp(feat, thr, leaf, X, depth)
+            vals = forest_rows(feat, thr, leaf, X, depth)
             vals = jnp.maximum(vals.astype(single.dtype), 1.0)
             pred = jnp.full((J, P, P), INTRA_DC_BW, single.dtype).at[
                 :, idx_i, idx_j].set(vals.reshape(J, n_pairs))
@@ -489,6 +502,14 @@ class FusedFleet:
         self._scan_cache[key] = fn
         return fn
 
+    def _sweep_fn(self):
+        """jit'd `(carry0, singles[B,T], bgs[B,T]) -> (carry, outs)`: the
+        per-tick scan vmapped over B variants from one shared state."""
+        if "sweep" not in self._scan_cache:
+            self._scan_cache["sweep"] = jax.jit(jax.vmap(
+                self._scan_fn(detail=False), in_axes=(None, 0, 0)))
+        return self._scan_cache["sweep"]
+
     # ------------------------------------------------------------------
     def run(self, steps: int, events: Tuple[Timed, ...] = ()
             ) -> List[Dict[str, Any]]:
@@ -522,12 +543,8 @@ class FusedFleet:
         [B,T,N,N] schedules from :func:`make_schedule` over variant
         simulators. Returns stacked per-tick stats [B,T,...]."""
         st = self.state()
-        if "sweep" not in self._scan_cache:
-            scan = self._scan_fn(detail=False)
-            self._scan_cache["sweep"] = jax.jit(
-                jax.vmap(scan, in_axes=(None, 0, 0)))
         with jax.enable_x64(True):
-            _, outs = self._scan_cache["sweep"](
+            _, outs = self._sweep_fn()(
                 (jnp.asarray(st.cons), jnp.asarray(st.target)),
                 jnp.asarray(singles), jnp.asarray(bgs))
         return jax.tree_util.tree_map(np.asarray, outs)

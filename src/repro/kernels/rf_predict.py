@@ -14,7 +14,10 @@ contraction would round thresholds through the MXU's bf16 passes).
 Grid: one cell per sample block; the whole forest (feat/thr/leaf) is
 resident in VMEM per cell (e.g. 100 trees x depth 8 ~= 0.4 MB). The
 node axis is padded to whole 128-lane tiles, and each block writes its
-predictions as a [block, 1] column of a 2-D output.
+predictions as a [block, 1] column of a 2-D output. Index maps and
+loop bounds are typed int32, so the kernel also lowers for the TPU when
+it is traced under `jax.enable_x64` (as the fused fleet tick is), where
+Python ints would become i64 and Mosaic refuses them.
 
 ``interpret=None`` (the default) runs the interpreter only on the CPU
 backend (`repro.kernels.interpret_default`).
@@ -25,6 +28,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels import interpret_default
@@ -59,9 +63,19 @@ def _rf_kernel(feat_ref, thr_ref, leaf_ref, x_ref, out_ref, *, depth: int,
             node = 2 * node + 1 + go_right
         return acc + pick(node - n_nodes == leaf_k, leaf_t)
 
-    acc = jax.lax.fori_loop(0, n_trees, tree_body,
+    acc = jax.lax.fori_loop(np.int32(0), np.int32(n_trees), tree_body,
                             jnp.zeros((BS, 1), jnp.float32))
     out_ref[...] = acc / n_trees
+
+
+def _whole(i):
+    """Index map of a block that spans the whole array (int32 under x64)."""
+    return jnp.int32(0), jnp.int32(0)
+
+
+def _rows(i):
+    """Index map of the i-th row block (int32 under x64)."""
+    return i, jnp.int32(0)
 
 
 def _pad_lanes(a: jax.Array) -> jax.Array:
@@ -89,12 +103,12 @@ def rf_predict_pallas(feat: jax.Array, thr: jax.Array, leaf: jax.Array,
                           n_nodes=n_nodes),
         grid=(npad // block,),
         in_specs=[
-            pl.BlockSpec(feat.shape, lambda i: (0, 0)),
-            pl.BlockSpec(thr.shape, lambda i: (0, 0)),
-            pl.BlockSpec(leaf.shape, lambda i: (0, 0)),
-            pl.BlockSpec((block, F), lambda i: (i, 0)),
+            pl.BlockSpec(feat.shape, _whole),
+            pl.BlockSpec(thr.shape, _whole),
+            pl.BlockSpec(leaf.shape, _whole),
+            pl.BlockSpec((block, F), _rows),
         ],
-        out_specs=pl.BlockSpec((block, 1), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((block, 1), _rows),
         out_shape=jax.ShapeDtypeStruct((npad, 1), jnp.float32),
         interpret=interpret,
         name="rf_predict",

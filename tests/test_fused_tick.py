@@ -1,6 +1,10 @@
 """The fused fleet tick (repro.fleet.fused): each jax-port stage pinned
 against its numpy reference, and the whole scanned program pinned
 against the sequential `FleetController.tick` loop."""
+import importlib.util
+import os
+import sys
+
 import numpy as np
 import pytest
 
@@ -15,8 +19,9 @@ from repro.fleet import (BatchedRfPredictor, FleetController, FusedFleet,
                          JobSpec, default_fleet_forest, make_schedule)
 from repro.fleet import arbiter
 from repro.fleet.fused import (aimd_step_jnp, connection_budgets_jnp,
-                               global_ranges_jnp, link_shares_jnp,
-                               relations_jnp, split_budget_jnp)
+                               forest_rows, global_ranges_jnp,
+                               link_shares_jnp, relations_jnp,
+                               split_budget_jnp)
 from repro.fleet.scenario import FleetEngine, FleetScenarioSpec
 from repro.scenarios.events import (CrossTraffic, DiurnalCycle, JobArrive,
                                     LinkDegrade, LinkRestore, at)
@@ -158,6 +163,55 @@ def test_aimd_port_exact():
                 np.testing.assert_array_equal(np.asarray(new_c), cons)
                 np.testing.assert_allclose(np.asarray(new_t), target,
                                            rtol=1e-12, atol=1e-12)
+
+
+def _plain_forest_predict():
+    """The benchmark's plain numpy forest (imports nothing of repro)."""
+    path = os.path.join(os.path.dirname(__file__), "..", "bench",
+                        "reference", "fleet_tick.py")
+    spec = importlib.util.spec_from_file_location("fleet_tick_ref", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod            # its dataclasses look it up
+    spec.loader.exec_module(mod)
+    return mod.forest_predict
+
+
+def _feature_rows(seed, n):
+    """Table-3 feature rows [n, 6] in float32 at fleet magnitudes."""
+    rng = np.random.default_rng(seed)
+    return np.stack([
+        np.full(n, 4.0), rng.uniform(50.0, 2500.0, n),
+        rng.uniform(0.05, 0.98, n), rng.uniform(0.02, 0.98, n),
+        rng.integers(0, 40, n).astype(float),
+        rng.uniform(300.0, 12000.0, n)], axis=-1).astype(np.float32)
+
+
+def test_forest_stage_equals_sequential_predictor():
+    """The fused tick's forest stage, jitted under x64 as the tick runs
+    it, returns the sequential tick's `predict_rows` bits (no floor hit),
+    stays within the online limit of the plain forest, and under vmap
+    gives each variant the rows of its own batch."""
+    rf = _forest()
+    bat = BatchedRfPredictor(rf)
+    feat, thr, leaf = (jnp.asarray(a) for a in rf.packed())
+    R = 3 * 4 * 3                           # J * P * (P - 1) of JOBS
+    X = _feature_rows(0, R)
+    Xb = np.stack([X, _feature_rows(1, R)])
+    with jax.enable_x64(True):
+        stage = jax.jit(lambda x: forest_rows(feat, thr, leaf, x, rf.depth))
+        got = np.asarray(stage(jnp.asarray(X)))
+        got_b = np.asarray(jax.vmap(stage)(jnp.asarray(Xb)))
+    assert got.dtype == np.float32 and got.shape == (R,)
+    assert np.all(got > 1.0)
+    np.testing.assert_array_equal(got.astype(np.float64),
+                                  bat.predict_rows(X))
+    plain = _plain_forest_predict()(*rf.packed(), X, rf.depth)
+    assert np.max(np.abs(got - plain) / np.abs(plain)) <= 1e-5
+    assert got_b.shape == (2, R)
+    for b in range(2):
+        np.testing.assert_array_equal(got_b[b].astype(np.float64),
+                                      bat.predict_rows(Xb[b]))
+    assert not np.array_equal(got_b[0], got_b[1])
 
 
 # ----------------------------------------------------------------------

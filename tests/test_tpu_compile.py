@@ -7,9 +7,11 @@ Nothing runs, so these say nothing about results or times.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.forest import RandomForest
 from repro.fleet import BatchedRfPredictor, FleetController, JobSpec, \
     default_fleet_forest
 from repro.kernels.placement_cost import _eval_jit
@@ -47,17 +49,21 @@ def spec(topo):
                                                      sharding=one_chip)
 
 
-# fleet launches (J * P * (P - 1) rows) and a 4096-row batch
+# fleet launches (J * P * (P - 1) rows) and a 4096-row batch, with x64
+# off (the sequential tick) and on (the fused tick traces under it)
+@pytest.mark.parametrize("x64", [False, True])
 @pytest.mark.parametrize("rows,trees,depth", [(96, 8, 5), (448, 100, 10),
                                               (4096, 100, 8)])
-def test_rf_kernel_compiles(spec, rows, trees, depth):
+def test_rf_kernel_compiles(spec, rows, trees, depth, x64):
     nn = 2 ** depth - 1
-    compiled = jax.jit(
-        lambda f, t, l, x: rf_predict_pallas(f, t, l, x, depth=depth,
-                                             interpret=False)
-    ).lower(spec((trees, nn), jnp.int32), spec((trees, nn), jnp.float32),
-            spec((trees, nn + 1), jnp.float32),
-            spec((rows, 6), jnp.float32)).compile()
+    with jax.enable_x64(x64):
+        compiled = jax.jit(
+            lambda f, t, l, x: rf_predict_pallas(f, t, l, x, depth=depth,
+                                                 interpret=False)
+        ).lower(spec((trees, nn), jnp.int32),
+                spec((trees, nn), jnp.float32),
+                spec((trees, nn + 1), jnp.float32),
+                spec((rows, 6), jnp.float32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -101,6 +107,42 @@ def test_fused_tick_scan_compiles(spec):
             (spec((J, P, P), jnp.int32), spec((J, P, P), jnp.float64)),
             spec((T, N, N), jnp.float64),
             spec((T, N, N), jnp.float64)).compile()
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Lower the Pallas kernels that resolve `interpret` themselves as
+    they would be on the chip, with no interpreted trace reused from
+    (or left to) the CPU tests of this process."""
+    monkeypatch.setattr("repro.kernels.rf_predict.interpret_default",
+                        lambda: False)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def test_fused_sweep_compiles(spec, compiled_kernels):
+    """The vmapped sweep at the benchmark's fleet8-aws shapes (one job on
+    8 DCs, a 100-tree depth-10 forest, 16 variants x 32 ticks) holds the
+    RF kernel as a custom call inside the scan."""
+    sim = WanSimulator(seed=0, fluct_sigma=0.0, snapshot_sigma=0.0,
+                       runtime_sigma=0.0, host_sigma=0.0)
+    # only the tables' shapes reach the compiler: skip the ~30-s fit
+    rf = RandomForest(n_trees=100, depth=10)
+    rf.feat = np.zeros((100, 1023), np.int32)
+    rf.thr = np.zeros((100, 1023), np.float32)
+    rf.leaf = np.ones((100, 1024), np.float32)
+    fleet = FleetController(sim, BatchedRfPredictor(rf), m_total=8,
+                            jobs=(JobSpec("gda", dcs=tuple(range(8))),))
+    ff = fleet.fused()
+    J, P, N, B, T = ff.J, ff.P, ff.N, 16, 32
+    assert (J, P, N) == (1, 8, 8)
+    with jax.enable_x64(True):
+        compiled = ff._sweep_fn().lower(
+            (spec((J, P, P), jnp.int32), spec((J, P, P), jnp.float64)),
+            spec((B, T, N, N), jnp.float64),
+            spec((B, T, N, N), jnp.float64)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_placement_evaluator_compiles(spec):
